@@ -2,8 +2,6 @@
 from __future__ import annotations
 
 import json
-import sys
-import traceback
 
 
 def main() -> None:
@@ -20,19 +18,11 @@ def main() -> None:
         ("train_step (repro.perf remat/fused policies)", train_step.run),
     ]
     print("name,us_per_call,derived")
-    failures = 0
-    for title, fn in suites:
-        try:
-            rows = fn()
-        except Exception:
-            traceback.print_exc()
-            failures += 1
-            continue
-        for row in rows:
+    # a failing suite raises: no later row can pass for a complete run
+    for _, fn in suites:
+        for row in fn():
             print(f"{row['name']},{row['us_per_call']},"
                   f"\"{json.dumps(row['derived'])}\"")
-    if failures:
-        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -1,83 +1,71 @@
 """Benchmark: mesh-layout scaling of the RL train step
 (``repro.distributed`` tentpole).
 
-Spawns one subprocess per mesh layout (the host-device-count XLA flag must
-be set before jax initializes) over dp×mp ∈ {1×1, 2×1, 4×1, 2×2} faked CPU
-devices, trains a few reduced-scale steps, and reports mean post-compile
-step time plus the per-device state bytes under the active PartitionPlan.
-On faked CPU host devices all "devices" share the same cores, so this
-measures *overhead* of the sharded paths (resharding + collectives +
-gradient accumulation), not speedup — the derived column reports the
-slowdown factor vs single-device, which should stay near 1 for dp-only
-layouts (params replicated, one grad all-reduce per step) and shows the
-gather/reduce-scatter cost the model axis adds in exchange for the
-per-device memory drop (``state_per_device_bytes``).
+Trains a few reduced-scale steps under each dp×mp layout in {1×1, 2×1,
+4×1, 2×2} that the devices present can hold, all in this one process (a
+chip belongs to one process at a time, so no child processes), and
+reports mean post-compile step time plus the per-device state bytes under
+the active PartitionPlan.  Layouts needing more devices than exist are
+skipped with a note on stderr.  Every row names the device it ran on.
+
+On XLA:CPU, give the process faked host devices before JAX starts
+(``XLA_FLAGS=--xla_force_host_platform_device_count=4``).  They share the
+same cores, so there this measures the *overhead* of the sharded paths
+(resharding + collectives + gradient accumulation), not speedup — the
+derived column reports the slowdown factor vs single-device, which should
+stay near 1 for dp-only layouts and shows the gather/reduce-scatter cost
+the model axis adds in exchange for the per-device memory drop
+(``state_per_device_bytes``).
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
+import time
 from typing import Dict, List
 
 STEPS = 4
 LAYOUTS = ((1, 1), (2, 1), (4, 1), (2, 2))
 
-_CHILD = r"""
-import json, time
-import jax, jax.numpy as jnp
-from repro import configs, registry
-from repro.config import DistConfig, FlowRLConfig, OptimConfig, RewardSpec
-from repro.perf.memory import state_bytes
 
-dp, mp = {dp}, {mp}
-flow = FlowRLConfig(num_steps=4, group_size=4, latent_tokens=8, latent_dim=8,
-                    clip_range=0.2,
-                    rewards=(RewardSpec("text_render", 1.0,
-                             args={{"latent_dim": 8, "latent_tokens": 8}}),))
-opt = OptimConfig(lr=1e-3, total_steps=50, warmup_steps=2)
-key = jax.random.PRNGKey(0)
-tr = registry.build("trainer", "flow_grpo", configs.get_reduced("flux_dit"),
-                    flow, opt, key=key,
-                    dist=DistConfig(data_parallel=dp, model_parallel=mp))
-cond = jax.random.normal(key, (4, 4, 512), jnp.float32)
-tr.step(cond, key, it=0)                         # compile
-t0 = time.time()
-for it in range(1, 1 + {steps}):
-    m = tr.step(cond, key, it=it)
-jax.block_until_ready(tr.state.params)
-dt = (time.time() - t0) / {steps}
-print(json.dumps({{"dp": dp, "mp": mp, "devices": jax.local_device_count(),
-                   "step_s": dt, "state": state_bytes(tr)}}))
-"""
+def _step_time(dp: int, mp: int) -> Dict:
+    import jax
+    import jax.numpy as jnp
+    from repro import configs, registry
+    from repro.config import DistConfig, FlowRLConfig, OptimConfig, RewardSpec
+    from repro.perf.memory import state_bytes
 
-
-def _child_env(n_devices: int) -> Dict[str, str]:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count="
-                        f"{n_devices}")
-    env["JAX_PLATFORMS"] = "cpu"
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    src = os.path.join(here, "src")
-    env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
-                         if env.get("PYTHONPATH") else src)
-    return env
+    flow = FlowRLConfig(num_steps=4, group_size=4, latent_tokens=8,
+                        latent_dim=8, clip_range=0.2,
+                        rewards=(RewardSpec("text_render", 1.0,
+                                            args={"latent_dim": 8,
+                                                  "latent_tokens": 8}),))
+    opt = OptimConfig(lr=1e-3, total_steps=50, warmup_steps=2)
+    key = jax.random.PRNGKey(0)
+    tr = registry.build("trainer", "flow_grpo",
+                        configs.get_reduced("flux_dit"), flow, opt, key=key,
+                        dist=DistConfig(data_parallel=dp, model_parallel=mp))
+    cond = jax.random.normal(key, (4, 4, 512), jnp.float32)
+    jax.block_until_ready(tr.step(cond, key, it=0))          # compile
+    t0 = time.perf_counter()
+    for it in range(1, 1 + STEPS):
+        tr.step(cond, key, it=it)
+    jax.block_until_ready(tr.state)
+    return {"step_s": (time.perf_counter() - t0) / STEPS,
+            "state": state_bytes(tr)}
 
 
 def run() -> List[Dict]:
+    import jax
+    n = jax.local_device_count()
+    dev = jax.local_devices()[0]
     rows: List[Dict] = []
     base_s = None
     for dp, mp in LAYOUTS:
-        code = _CHILD.format(dp=dp, mp=mp, steps=STEPS)
-        proc = subprocess.run([sys.executable, "-c", code],
-                              capture_output=True, text=True,
-                              env=_child_env(dp * mp), timeout=540)
-        if proc.returncode != 0:
-            raise RuntimeError(f"dp={dp} mp={mp} child failed:\n"
-                               f"{proc.stderr}")
-        out = json.loads(proc.stdout.strip().splitlines()[-1])
+        if dp * mp > n:
+            print(f"scaling: skipping dp={dp} mp={mp}: needs {dp * mp} "
+                  f"devices, {n} present", file=sys.stderr)
+            continue
+        out = _step_time(dp, mp)
         if base_s is None:
             base_s = out["step_s"]
         # dp-only rows keep their historical names so stored benchmark
@@ -87,7 +75,9 @@ def run() -> List[Dict]:
         rows.append({
             "name": name,
             "us_per_call": round(out["step_s"] * 1e6, 1),
-            "derived": {"devices": out["devices"],
+            "derived": {"platform": dev.platform,
+                        "device_kind": dev.device_kind,
+                        "devices": dp * mp,
                         "overhead_vs_dp1": round(out["step_s"] / base_s, 3),
                         "state_per_device_bytes":
                             out["state"]["per_device_bytes"],

@@ -18,6 +18,7 @@ Dynamics (select via ``sde_type`` — one config knob, paper §3.1):
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -27,7 +28,10 @@ from repro import registry
 
 F32 = jnp.float32
 _EPS = 1e-4
-LOG2PI = jnp.log(2.0 * jnp.pi)
+# a host constant: jnp.log(2π) evaluated on a v5e is off by 3.4e-5, which
+# the per-element log-density sum over a 64 K-float latent turns into a 1.1
+# shift of every log-prob (and of the GRPO ratio against the fused kernel)
+LOG2PI = math.log(2.0 * math.pi)
 
 
 def _sum_dims(x: jax.Array) -> jax.Array:

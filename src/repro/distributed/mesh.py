@@ -96,18 +96,6 @@ def train_mesh(dist: DistConfig) -> Optional[Mesh]:
     devices = jax.local_devices()[:dp * mp]
     if mp == 1:
         return Mesh(devices, (DATA_AXIS,))
-    if not jax.config.jax_threefry_partitionable:
-        # non-partitionable threefry is not sharding-invariant on a 2-D
-        # mesh: a batch-sharded jax.random draw produces different bits
-        # than the same program replicated, which would make 2-D rollouts
-        # sample different trajectories than every other layout.  The
-        # partitionable implementation is invariant by construction.
-        # Flipping the flag changes the random stream, so it happens only
-        # when a model axis actually exists — dp-only and single-device
-        # runs keep today's bits exactly; within an mp>1 process every
-        # layout (including the single-device reference the equivalence
-        # tests compare against) then draws the same stream.
-        jax.config.update("jax_threefry_partitionable", True)
     return Mesh(np.asarray(devices).reshape(dp, mp),
                 (DATA_AXIS, MODEL_AXIS))
 

@@ -20,7 +20,6 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.rollout import Trajectory, rollout, rollout_keyed
@@ -43,10 +42,11 @@ def make_rollout_sharded(adapter, scheduler, num_steps: int, mesh: Mesh,
 
     out_specs = Trajectory(xs=P(None, DATA_AXIS), logps=P(None, DATA_AXIS),
                            ts=P(), sde_mask=P(), cond=P(DATA_AXIS))
-    # check_rep=False: ts/sde_mask are replicated by construction (identical
+    # check_vma=False: ts/sde_mask are replicated by construction (identical
     # computation per shard) but shard_map cannot prove it
-    sharded = shard_map(local, mesh=mesh, in_specs=(P(), P(DATA_AXIS), P()),
-                        out_specs=out_specs, check_rep=False)
+    sharded = jax.shard_map(local, mesh=mesh,
+                            in_specs=(P(), P(DATA_AXIS), P()),
+                            out_specs=out_specs, check_vma=False)
     dp = mesh_dp(mesh)
 
     def run(params, cond: jax.Array, key: jax.Array) -> Trajectory:
@@ -107,11 +107,12 @@ def make_rollout_keyed_sharded(adapter, scheduler, num_steps: int,
                      Trajectory(xs=P(None, DATA_AXIS),
                                 logps=P(None, DATA_AXIS),
                                 ts=P(), sde_mask=P(), cond=P(DATA_AXIS)))
-        # check_rep=False: ts/sde_mask are replicated by construction
+        # check_vma=False: ts/sde_mask are replicated by construction
         # (identical computation per shard) but shard_map cannot prove it
-        sharded = shard_map(local, mesh=mesh,
-                            in_specs=(P(), P(DATA_AXIS), P(DATA_AXIS), P()),
-                            out_specs=out_specs, check_rep=False)
+        sharded = jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(P(), P(DATA_AXIS), P(DATA_AXIS), P()),
+            out_specs=out_specs, check_vma=False)
         _jitted = jax.jit(sharded)
 
     def run(params, cond, keys, sde_mask):

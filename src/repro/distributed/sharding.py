@@ -22,6 +22,7 @@ replicated schedule arrays.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -208,6 +209,18 @@ def check_batch_divisible(batch: int, mesh: Optional[Mesh],
 
 # ------------------------------------------------------------- jit wrappers
 
+def _on_mesh(fn: Callable, mesh: Mesh) -> Callable:
+    """``fn`` traced with ``mesh`` as the abstract mesh in context, so the
+    Pallas kernel wrappers of ``repro.kernels.ops`` can split their batch
+    over its data axis with ``shard_map`` (the TPU compiler cannot
+    partition a Mosaic kernel by itself)."""
+    @functools.wraps(fn)
+    def traced(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args)
+    return traced
+
+
 def _plan_jit(fn: Callable, in_shardings, out_shardings=None):
     """Shared constructor for the non-donating sharded entry points.  The
     donating wrappers (``jit_update``/``jit_fused_step``) call ``jax.jit``
@@ -229,7 +242,8 @@ def jit_sample(fn: Callable, mesh: Optional[Mesh], params_sharding=None):
         return jax.jit(fn)
     rep = replicated(mesh)
     psh = params_sharding if params_sharding is not None else rep
-    return _plan_jit(fn, (psh, batch_sharding(mesh, 0), rep, rep),
+    return _plan_jit(_on_mesh(fn, mesh),
+                     (psh, batch_sharding(mesh, 0), rep, rep),
                      traj_shardings(mesh))
 
 
@@ -274,7 +288,7 @@ def jit_fused_step(fn: Callable, mesh: Optional[Mesh], state_sharding=None,
     if with_reward_params:
         in_sh.append(rep)
     return jax.jit(
-        fn,
+        _on_mesh(fn, mesh),
         in_shardings=tuple(in_sh),
         out_shardings=(ssh, rep),
         donate_argnums=donate_argnums)
@@ -296,7 +310,7 @@ def jit_update(fn: Callable, mesh: Optional[Mesh], state_sharding=None, *,
     ssh = state_sharding if state_sharding is not None else rep
     esh = extras_sharding if extras_sharding is not None else rep
     return jax.jit(
-        fn,
+        _on_mesh(fn, mesh),
         in_shardings=(ssh, traj_shardings(mesh), batch_sharding(mesh, 0),
                       rep, esh),
         out_shardings=(ssh, rep),

@@ -2,6 +2,7 @@
 shape/dtype sweep tests assert against)."""
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -9,6 +10,7 @@ import jax.numpy as jnp
 
 F32 = jnp.float32
 NEG_INF = -1e30
+LOG2PI = math.log(2.0 * math.pi)     # host constant, see core/schedulers.py
 
 
 def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0
@@ -73,7 +75,7 @@ def sde_step_ref(v, x, t, t_next, eps, *, eta: float
     std = sigma * jnp.sqrt(delta)
     x_next = mean + std * eps.astype(F32)
     z = (x_next - mean) / std
-    logp = (-0.5 * (z * z + jnp.log(2.0 * jnp.pi)) - jnp.log(std))
+    logp = (-0.5 * (z * z + LOG2PI) - jnp.log(std))
     return x_next, logp.reshape(x.shape[0], -1).sum(-1)
 
 

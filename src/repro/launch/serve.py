@@ -27,6 +27,7 @@ import numpy as np
 from repro.api import Experiment, FlowSampler  # noqa: F401 (re-export)
 from repro.api.experiment import default_cli_config
 from repro.config import replace
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def serve_profile():
@@ -36,6 +37,7 @@ def serve_profile():
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     ap = Experiment.cli_parser("Flow-Factory sampling service")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=8)

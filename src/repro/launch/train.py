@@ -36,9 +36,11 @@ import jax
 
 from repro.api import Experiment
 from repro.distributed import resolve_axes
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     exp = Experiment.from_cli(argv)
     d = exp.describe()
     dp, mp = resolve_axes(exp.cfg.dist)
@@ -64,8 +66,6 @@ def main(argv=None) -> None:
             (d_cfg.batch_prompts, exp.cond_len, exp.cond_dim),
             jax.numpy.float32)
         for name, mem in tr.memory_stats(cond).items():
-            # analysis_dict degrades to {"error": str} on backends without
-            # memory_analysis support — report, don't crash the launch
             pretty = " ".join(
                 f"{k[:-len('_bytes')]}={v / 1e6:.2f}MB"
                 if k.endswith("_bytes") and isinstance(v, (int, float))

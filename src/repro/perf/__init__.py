@@ -28,7 +28,7 @@ Exactness contract (asserted in tests/test_perf.py / test_pipeline.py):
   replace recompute in the scan backward.
 """
 from repro.perf.fused import make_fused_step
-from repro.perf.memory import analysis_dict, update_memory
+from repro.perf.memory import analysis_dict, lower_step, update_memory
 from repro.perf.offload import (offload_param_store, prefetch_tree,
                                 reward_tower_report, tree_bytes)
 from repro.perf.policy import (REMAT_MODES, block_remat, remat_policy,
@@ -36,7 +36,8 @@ from repro.perf.policy import (REMAT_MODES, block_remat, remat_policy,
 
 __all__ = [
     "REMAT_MODES", "block_remat", "remat_policy", "resolve_policy_dtype",
-    "validate", "make_fused_step", "analysis_dict", "update_memory",
+    "validate", "make_fused_step", "analysis_dict", "lower_step",
+    "update_memory",
     "offload_param_store", "prefetch_tree", "reward_tower_report",
     "tree_bytes",
 ]

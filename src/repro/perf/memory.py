@@ -32,11 +32,13 @@ _FIELDS = {
 
 def analysis_dict(compiled) -> Dict[str, Optional[int]]:
     """``memory_analysis()`` as a plain dict (None where the backend does
-    not implement a field — CPU reports temp/argument/output)."""
-    try:
-        mem = compiled.memory_analysis()
-    except Exception as e:                 # backend without analysis support
-        return {"error": str(e)}
+    not implement a field — CPU reports temp/argument/output).  A backend
+    that returns no analysis at all raises: a missing number must not pass
+    for a measured one."""
+    mem = compiled.memory_analysis()
+    if mem is None:
+        raise RuntimeError(f"{jax.default_backend()} returned no "
+                           "memory_analysis for the compiled step")
     return {k: getattr(mem, attr, None) for k, attr in _FIELDS.items()}
 
 
@@ -66,10 +68,13 @@ def state_bytes(trainer) -> Dict[str, int]:
             "sharded_leaves": 0}
 
 
-def update_memory(trainer, cond: jax.Array) -> Dict[str, Dict]:
-    """AOT-compile the trainer's jitted update — and, when
-    ``perf.fuse_step`` is on, the fused step — for a ``cond`` prompt batch
-    of shape (P, Lc, cond_dim), and report the analysis byte counts.
+def lower_step(trainer, cond: jax.Array) -> Dict[str, Any]:
+    """Lower the programs one ``trainer.step`` runs, on shapes, for a
+    ``cond`` prompt batch of shape (P, Lc, cond_dim): ``sample``,
+    ``rewards`` and ``update``, plus ``fused`` when ``perf.fuse_step`` is
+    on.  Returns ``jax.stages.Lowered`` objects; compiling them ahead of
+    time fills jit's cache, so the first real step of the same shapes
+    reuses those executables instead of compiling again.
 
     Pure introspection: nothing executes and no live buffer is touched
     (lowering on structs never donates real state)."""
@@ -85,24 +90,39 @@ def update_memory(trainer, cond: jax.Array) -> Dict[str, Dict]:
         sde_mask=jax.ShapeDtypeStruct((T,), jnp.bool_),
         cond=jax.ShapeDtypeStruct((B, Lc, D), F32),
     )
+    x0 = jax.ShapeDtypeStruct((B, f.latent_tokens, f.latent_dim), F32)
     adv = jax.ShapeDtypeStruct((B,), F32)
     key = _struct(jax.random.PRNGKey(0))
     state = _struct(trainer.state)
     extras = _struct(trainer.update_extras())
-    from repro.perf.offload import reward_tower_report
-    out = {"update": analysis_dict(
-        trainer._update_jit.lower(state, traj, adv, key, extras).compile()),
-        "state": state_bytes(trainer),
-        # the frozen-tower footprint and what perf.offload_rewards frees
-        # from the device (host-side shape arithmetic, nothing compiles)
-        "reward_towers": reward_tower_report(trainer)}
+    reward_params = ((_struct(trainer._reward_store_host),)
+                     if trainer.offloads_rewards else ())
+    out = {
+        "sample": trainer._sample_jit.lower(state.params, traj.cond, key,
+                                            traj.sde_mask),
+        "rewards": trainer._rewards_jit.lower(x0, {"cond": traj.cond},
+                                              *reward_params),
+        "update": trainer._update_jit.lower(state, traj, adv, key, extras),
+    }
     if trainer._fused_jit is not None:
-        cond_g = jax.ShapeDtypeStruct((B, Lc, D), F32)
         it = jax.ShapeDtypeStruct((), jnp.int32)
-        mask = jax.ShapeDtypeStruct((T,), jnp.bool_)
-        fused_args = [state, cond_g, key, it, mask, extras]
-        if trainer.offloads_rewards:
-            fused_args.append(_struct(trainer._reward_store_host))
-        out["fused"] = analysis_dict(trainer._fused_jit.lower(
-            *fused_args).compile())
+        out["fused"] = trainer._fused_jit.lower(
+            state, traj.cond, key, it, traj.sde_mask, extras,
+            *reward_params)
+    return out
+
+
+def update_memory(trainer, cond: jax.Array) -> Dict[str, Dict]:
+    """AOT-compile the trainer's jitted update — and, when
+    ``perf.fuse_step`` is on, the fused step — for a ``cond`` prompt batch
+    of shape (P, Lc, cond_dim), and report the analysis byte counts."""
+    from repro.perf.offload import reward_tower_report
+    lowered = lower_step(trainer, cond)
+    out = {"update": analysis_dict(lowered["update"].compile()),
+           "state": state_bytes(trainer),
+           # the frozen-tower footprint and what perf.offload_rewards frees
+           # from the device (host-side shape arithmetic, nothing compiles)
+           "reward_towers": reward_tower_report(trainer)}
+    if "fused" in lowered:
+        out["fused"] = analysis_dict(lowered["fused"].compile())
     return out

@@ -25,13 +25,11 @@ Two independent mechanisms, one idea — device HBM should hold what the
   scan via ``jax.ad_checkpoint.checkpoint_name``.
 
 Backend notes: memory *kinds* are how XLA addresses host memory from
-within a compiled program.  Accelerator backends expose ``pinned_host``
-alongside the device default; the CPU backend's default memory already
-*is* the host (``unpinned_host`` is its only kind), so
-:func:`host_memory_kind` returns None there and :func:`offload_param_store`
-degrades to plain ``device_get`` numpy arrays — same semantics, and the
-"device" bytes accounted in :func:`reward_tower_report` are what an
-accelerator run would free.
+within a compiled program.  Every backend of the installed JAX (the CPU
+one included) exposes ``pinned_host`` alongside its ``device`` default, so
+the offloaded leaves stay jax arrays under a host-kind sharding.  A backend
+without a distinct host kind gets plain ``device_get`` numpy arrays on
+CPU; on an accelerator that is an error, never a silent move to numpy.
 """
 from __future__ import annotations
 
@@ -51,15 +49,11 @@ OFFLOAD_NAMES = ("velocity",)
 
 def host_memory_kind(device=None) -> Optional[str]:
     """A host memory kind addressable by ``device`` and distinct from its
-    default memory, or None when the default already lives on the host
-    (XLA:CPU) or the backend predates memory kinds."""
+    default memory, or None when the default already lives on the host."""
     if device is None:
         device = jax.local_devices()[0]
-    try:
-        kinds = {m.kind for m in device.addressable_memories()}
-        default = device.default_memory().kind
-    except Exception:                    # backend without memory-kind API
-        return None
+    kinds = {m.kind for m in device.addressable_memories()}
+    default = device.default_memory().kind
     for kind in _HOST_KINDS:
         if kind in kinds and kind != default:
             return kind
@@ -81,10 +75,15 @@ def tree_bytes(tree: Any) -> int:
 def offload_tree(tree: Any) -> Any:
     """Move a pytree to host memory.  On backends with a distinct host
     memory kind the leaves stay jax arrays under a host-kind sharding
-    (so :func:`prefetch_tree` is a pure memory-kind transfer); on CPU the
-    leaves become numpy arrays via one ``device_get``."""
+    (so :func:`prefetch_tree` is a pure memory-kind transfer); on a CPU
+    backend without one the leaves become numpy arrays via one
+    ``device_get``.  An accelerator without a host kind raises."""
     kind = host_memory_kind()
     if kind is None:
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"{jax.default_backend()} device exposes no host memory "
+                "kind; perf.offload_rewards cannot park the reward towers")
         return jax.device_get(tree)
     sharding = jax.sharding.SingleDeviceSharding(jax.local_devices()[0],
                                                  memory_kind=kind)

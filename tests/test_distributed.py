@@ -375,8 +375,8 @@ def train(dist):
             for it in range(3)]
     return tr, hist
 
-# 2-D FIRST: building the mesh enables partitionable threefry (sharding-
-# invariant RNG), so the single-device reference draws the same stream
+# jax's default partitionable threefry is sharding-invariant, so the
+# single-device reference draws the same random stream as the 2-D layout
 t22, h22 = train(DistConfig(data_parallel=2, model_parallel=2))
 t1, h1 = train(DistConfig())
 
@@ -473,3 +473,42 @@ def test_checkpoint_portable_across_mesh_layouts():
     layouts are a runtime choice, the on-disk layout is canonical."""
     out = _run_with_host_devices(_PORTABLE_SCRIPT)
     assert "PORTABLE-OK" in out
+
+
+_MESH_KERNEL_SCRIPT = r"""
+import os
+os.environ["REPRO_PALLAS"] = "interpret"
+import jax, jax.numpy as jnp
+import numpy as np
+from repro import distributed
+from repro.config import DistConfig
+from repro.distributed.sharding import _on_mesh, batch_sharding
+from repro.kernels import ops, ref
+
+mesh = distributed.train_mesh(DistConfig(data_parallel=2, model_parallel=2))
+ks = jax.random.split(jax.random.PRNGKey(0), 3)
+v, x, eps = (jax.random.normal(k, (8, 16, 8)) for k in ks)
+
+def step(v, x, eps):
+    return ops.sde_step(v, x, eps, 0.9, 0.8, eta=0.7)
+
+# the trainer's mesh jits trace under the mesh the same way
+fn = jax.jit(_on_mesh(step, mesh), in_shardings=(batch_sharding(mesh, 0),) * 3)
+args = [jax.device_put(a, batch_sharding(mesh, 0)) for a in (v, x, eps)]
+assert "shard_map" in str(fn.trace(*args).jaxpr), "kernel not split"
+xn, lp = fn(*args)
+xr, lr = ref.sde_step_ref(v, x, 0.9, 0.8, eps, eta=0.7)
+np.testing.assert_allclose(xn, xr, atol=1e-5, rtol=1e-5)
+np.testing.assert_allclose(lp, lr, atol=1e-3, rtol=1e-5)
+# without a mesh in context the kernel is called directly
+assert "shard_map" not in str(jax.make_jaxpr(step)(v, x, eps))
+print("MESH-KERNEL-OK")
+"""
+
+
+def test_pallas_kernels_split_over_the_data_axis_under_a_mesh():
+    """Mosaic kernels cannot be partitioned by the TPU compiler: under a
+    trainer mesh jit the ops wrappers run them per data shard through
+    shard_map, with the same results as the reference."""
+    out = _run_with_host_devices(_MESH_KERNEL_SCRIPT)
+    assert "MESH-KERNEL-OK" in out

@@ -280,3 +280,35 @@ def test_grpo_loss_diff_gradient():
     g_ref = jax.grad(jnp_loss)(lpn)
     g_kern = jax.grad(kern_loss)(lpn)
     np.testing.assert_allclose(g_kern, g_ref, atol=1e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+def test_cpu_only_pallas_modes_refused_on_tpu(monkeypatch, mode):
+    """On a TPU backend the compiled kernels run: the CPU-only dispatch
+    settings raise instead of running the reference or the interpreter
+    under the name of a chip run."""
+    from repro.kernels import ops
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("REPRO_PALLAS", mode)
+    with pytest.raises(RuntimeError, match=f"REPRO_PALLAS={mode}"):
+        ops.pallas_enabled()
+
+
+def test_tpu_backend_dispatches_compiled_kernels(monkeypatch):
+    from repro.kernels import ops
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_PALLAS", raising=False)
+    assert ops._mode() == "on"
+
+
+def test_interpret_mode_still_runs_on_cpu(monkeypatch):
+    from repro.kernels import ops
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    assert jax.default_backend() == "cpu"
+    assert ops._mode() == "interpret"
+    ks = jax.random.split(KEY, 3)
+    v, x, eps = (jax.random.normal(k, (2, 8, 16)) for k in ks)
+    xn, lp = ops.sde_step(v, x, eps, 0.9, 0.8, eta=0.7)
+    xr, lr = ref.sde_step_ref(v, x, 0.9, 0.8, eps, eta=0.7)
+    np.testing.assert_allclose(xn, xr, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(lp, lr, atol=1e-3, rtol=1e-5)

@@ -300,3 +300,31 @@ def test_fused_memory_stats_under_mesh():
               dist=DistConfig(data_parallel=4))
     mem = tr.memory_stats(cond)
     assert mem["update"]["temp_bytes"] and mem["fused"]["temp_bytes"]
+
+
+def test_memory_analysis_missing_raises():
+    """A backend that returns no analysis raises: a missing number never
+    passes for a measured one (no ``{"error": ...}`` stand-in)."""
+    from repro.perf import analysis_dict
+
+    class NoAnalysis:
+        def memory_analysis(self):
+            return None
+
+    with pytest.raises(RuntimeError, match="memory_analysis"):
+        analysis_dict(NoAnalysis())
+    compiled = jax.jit(lambda x: x * 2).lower(jnp.ones((8,))).compile()
+    assert analysis_dict(compiled)["argument_bytes"] == 32
+
+
+def test_offload_without_host_memory_kind_raises_on_accelerator(monkeypatch):
+    """perf.offload_rewards never quietly moves the towers to host numpy on
+    an accelerator that exposes no host memory kind; the CPU backend keeps
+    its device_get path."""
+    from repro.perf import offload
+    monkeypatch.setattr(offload, "host_memory_kind", lambda device=None: None)
+    tree = {"w": jnp.ones((4,))}
+    assert isinstance(offload.offload_tree(tree)["w"], np.ndarray)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="host memory kind"):
+        offload.offload_tree(tree)

@@ -80,7 +80,8 @@ from repro import configs
 from repro.config import InputShape
 from repro.launch.specs import build_step
 from repro.launch import hlo_stats
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh(4, 2)
 cfg = configs.get_reduced("qwen3-32b")
 shape = InputShape("t", 128, 8, "train")
 with mesh:
@@ -107,7 +108,8 @@ import jax
 from repro import configs
 from repro.config import InputShape
 from repro.launch.specs import build_step
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh(4, 2)
 for arch in ("mamba2-370m", "zamba2-2.7b", "deepseek-v2-236b"):
     cfg = configs.get_reduced(arch)
     shape = InputShape("d", 256, 8, "decode")
@@ -164,3 +166,34 @@ ENTRY %main () -> f32[8] {
     assert coll["all-reduce"]["count"] == 12
     assert coll["all-gather"]["count"] == 1
     assert coll["all-reduce"]["result_bytes"] == 12 * 32
+
+
+def test_compile_cache_defaults_to_fixed_dir_in_checkout(monkeypatch):
+    from repro.launch import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable_compile_cache()
+        assert got == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_env_dir_is_left_to_jax(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_importing_repro_leaves_compile_cache_off():
+    code = ("import jax, repro, repro.api, repro.launch.train; "
+            "print(repr(jax.config.jax_compilation_cache_dir))")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300, env=env)
+    assert r.stdout.strip().splitlines()[-1] == "None", r.stderr[-2000:]
