@@ -23,8 +23,7 @@ export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 DIST_FLAGS := --xla_force_host_platform_device_count=4
 
 .PHONY: verify deps-check lint test test-interpret test-dist test-serve \
-	test-perf-dist test-pipeline fuzz-serve smoke smoke-dist smoke-dist-2d \
-	bench-train
+	test-perf-dist test-pipeline fuzz-serve smoke smoke-dist smoke-dist-2d
 
 verify: deps-check lint test test-interpret test-dist test-serve \
 	test-perf-dist test-pipeline fuzz-serve
@@ -98,10 +97,6 @@ test-perf-dist:
 # composition test (skipped in `make test`) executes too.
 test-pipeline:
 	XLA_FLAGS="$(DIST_FLAGS)" $(PY) -m pytest -x -q tests/test_pipeline.py
-
-# train-step perf trajectory: writes BENCH_train_step.json at the repo root
-bench-train:
-	$(PY) -m benchmarks.train_step
 
 smoke:
 	$(PY) -m repro.launch.train --reduced --steps 2 \

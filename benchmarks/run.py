@@ -6,7 +6,7 @@ import json
 
 def main() -> None:
     from benchmarks import preprocessing, reward_curves, roofline, \
-        scaling, sde_dynamics, serving, train_step
+        scaling, sde_dynamics, serving
 
     suites = [
         ("sde_dynamics (paper Table 1)", sde_dynamics.run),
@@ -15,7 +15,6 @@ def main() -> None:
         ("roofline (deliverable g)", roofline.run),
         ("scaling (repro.distributed mesh layouts)", scaling.run),
         ("serving (repro.serving bucketed engine)", serving.run),
-        ("train_step (repro.perf remat/fused policies)", train_step.run),
     ]
     print("name,us_per_call,derived")
     # a failing suite raises: no later row can pass for a complete run

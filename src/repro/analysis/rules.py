@@ -741,8 +741,12 @@ class R005DonatedBufferReuse(Rule):
                 if isinstance(s, (ast.FunctionDef, ast.AsyncFunctionDef,
                                   ast.ClassDef)):
                     continue
+                # a with-statement's body runs once, in line: it is walked
+                # by the recursion below, so only its items belong here
+                own = ([n for item in s.items for n in shallow_walk(item)]
+                       if isinstance(s, ast.With) else list(shallow_walk(s)))
                 # 1. reads of already-donated buffers in this statement
-                for n in shallow_walk(s):
+                for n in own:
                     if isinstance(n, (ast.Name, ast.Attribute)) and \
                             isinstance(getattr(n, "ctx", None), ast.Load):
                         src = expr_src(n)
@@ -758,8 +762,7 @@ class R005DonatedBufferReuse(Rule):
                 # 2. does this statement donate something?  (a Return's
                 # donation can never be read afterwards — skip it)
                 new_donations: List[Tuple[str, ast.Call, str]] = []
-                for n in (() if isinstance(s, ast.Return)
-                          else shallow_walk(s)):
+                for n in (() if isinstance(s, ast.Return) else own):
                     if not isinstance(n, ast.Call):
                         continue
                     pos = call_donates(n)

@@ -188,8 +188,9 @@ class PeriodicCheckpoint(Callback):
 
     def on_step(self, loop, step, metrics):
         if self.every and (step + 1) % self.every == 0:
-            checkpoint.save_checkpoint(self.ckpt_dir, step + 1,
-                                       loop.trainer.state)
+            with jax.profiler.TraceAnnotation("repro.checkpoint.save"):
+                checkpoint.save_checkpoint(self.ckpt_dir, step + 1,
+                                           loop.trainer.state)
 
 
 class EarlyStop(Callback):
@@ -281,8 +282,12 @@ class TrainLoop:
         rewards/fused jit); fetching per-metric with float() cost ~8
         separate syncs per step.  Converting at the transfer site keeps
         the loop body sync-free (jaxlint R002/R007)."""
-        it, metrics, t_dispatch = pending.popleft()
-        m = jax.tree.map(float, jax.device_get(metrics))
+        with jax.profiler.TraceAnnotation("repro.loop.drain"):
+            self._drain(*pending.popleft())
+
+    def _drain(self, it: int, metrics: Any, t_dispatch: float) -> None:
+        with jax.profiler.TraceAnnotation("repro.loop.fetch"):
+            m = jax.tree.map(float, jax.device_get(metrics))
         now = time.time()
         self._n_drained += 1
         # end-to-end drained-step rate over a window anchored at the SECOND
@@ -307,8 +312,9 @@ class TrainLoop:
             if k.startswith("reward/"):
                 row[k] = v
         self.history.append(row)
-        for cb in self.callbacks:
-            cb.on_step(self, it, row)
+        with jax.profiler.TraceAnnotation("repro.loop.callbacks"):
+            for cb in self.callbacks:
+                cb.on_step(self, it, row)
 
     # ------------------------------------------------------------------ run
     def run(self) -> List[Dict[str, Any]]:
@@ -325,34 +331,40 @@ class TrainLoop:
         for it in range(self.start_step, self.steps):
             if self._stop:
                 break
-            prompts = next_prompts if next_prompts is not None \
-                else next(stream)
-            next_prompts = None
-            cond = self.provider.get(prompts)["cond"]
-            t_dispatch = time.time()
-            pending.append((it, self.trainer.step(cond, self.key, it=it),
-                            t_dispatch))
-            if it == self.start_step + 1:  # second dispatch: post-compile
-                self._t_window0 = t_dispatch
-            # overlap host work with the in-flight device step(s): pull the
-            # next prompt batch, warm its conditions, start the reward-tower
-            # H2D copy — all before blocking on any drain
-            if it + 1 < self.steps:
-                next_prompts = next(stream)
-                if can_prefetch:
-                    self.provider.prefetch(next_prompts)
-            if can_prefetch_rewards:
-                self.trainer.prefetch_reward_params()
-            # a sync-hungry callback (checkpoint) forces a full drain so it
-            # observes trainer.state exactly as of this step; otherwise keep
-            # at most `pipeline` steps in flight (duck-typed: user callbacks
-            # need not subclass Callback, so wants_sync is optional)
-            barrier = any(
-                getattr(cb, "wants_sync", _no_sync)(self, it)
-                for cb in self.callbacks)
-            limit = 0 if barrier else self.pipeline - 1
-            while len(pending) > limit:
-                self._drain_one(pending)
+            # host spans (profiler only): what the host does in each step
+            with jax.profiler.StepTraceAnnotation("repro.step", step_num=it):
+                prompts = next_prompts if next_prompts is not None \
+                    else next(stream)
+                next_prompts = None
+                with jax.profiler.TraceAnnotation("repro.loop.conditions"):
+                    cond = self.provider.get(prompts)["cond"]
+                t_dispatch = time.time()
+                with jax.profiler.TraceAnnotation("repro.loop.dispatch"):
+                    out = self.trainer.step(cond, self.key, it=it)
+                pending.append((it, out, t_dispatch))
+                if it == self.start_step + 1:  # second dispatch: compiled
+                    self._t_window0 = t_dispatch
+                # overlap host work with the in-flight device step(s): pull
+                # the next prompt batch, warm its conditions, start the
+                # reward-tower H2D copy — all before blocking on any drain
+                with jax.profiler.TraceAnnotation("repro.loop.prefetch"):
+                    if it + 1 < self.steps:
+                        next_prompts = next(stream)
+                        if can_prefetch:
+                            self.provider.prefetch(next_prompts)
+                    if can_prefetch_rewards:
+                        self.trainer.prefetch_reward_params()
+                # a sync-hungry callback (checkpoint) forces a full drain so
+                # it observes trainer.state exactly as of this step;
+                # otherwise keep at most `pipeline` steps in flight
+                # (duck-typed: user callbacks need not subclass Callback, so
+                # wants_sync is optional)
+                barrier = any(
+                    getattr(cb, "wants_sync", _no_sync)(self, it)
+                    for cb in self.callbacks)
+                limit = 0 if barrier else self.pipeline - 1
+                while len(pending) > limit:
+                    self._drain_one(pending)
         while pending:                    # drain the tail (and on stop: the
             self._drain_one(pending)      # already-dispatched steps DID run)
         for cb in self.callbacks:

@@ -129,8 +129,12 @@ class BaseTrainer:
             self._update, self.mesh, self.state_sharding,
             donate=self.dist.donate_state and self.donate_state_ok,
             extras_sharding=self.update_extras_sharding())
+        # keeps ``_rewards``' name, so the program is ``jit__rewards``
         self._rewards_jit = distributed.jit_rewards(
-            functools.partial(self._rewards, group_size=flow_cfg.group_size),
+            functools.update_wrapper(
+                functools.partial(self._rewards,
+                                  group_size=flow_cfg.group_size),
+                self._rewards),
             self.mesh, with_params=self.perf.offload_rewards)
         self._fused_jit = (perf_lib.make_fused_step(self)
                            if self.perf.fuse_step else None)
@@ -290,8 +294,9 @@ class BaseTrainer:
             (loss, aux), grads = jax.value_and_grad(
                 self.loss_fn, has_aux=True)(state.params, traj, adv, key,
                                             *extras)
-        grads, gnorm = optim.clip_by_global_norm(grads,
-                                                 self.opt_cfg.grad_clip)
+        with jax.named_scope("optim_adamw"):
+            grads, gnorm = optim.clip_by_global_norm(grads,
+                                                     self.opt_cfg.grad_clip)
         lr = self._lr(state.opt.step)
         new_p, new_opt = self.optimizer.update(state.params, grads, state.opt,
                                                self.opt_cfg, lr)
@@ -330,16 +335,20 @@ class BaseTrainer:
                     self.state, cond_g, key, jnp.int32(it), mask, extras)
             return metrics
         k_s, k_u = jax.random.split(jax.random.fold_in(key, it))
-        traj = self.sample(self.state.params, cond, k_s, it)
+        # host spans (profiler only): the enqueue of each program
+        with jax.profiler.TraceAnnotation("repro.trainer.sample"):
+            traj = self.sample(self.state.params, cond, k_s, it)
         cond_meta = {"cond": traj.cond}
-        if self.offloads_rewards:
-            _, adv, reward_stats = self._rewards_jit(
-                traj.x0, cond_meta, self._take_reward_params())
-        else:
-            _, adv, reward_stats = self._rewards_jit(traj.x0, cond_meta)
+        with jax.profiler.TraceAnnotation("repro.trainer.rewards"):
+            if self.offloads_rewards:
+                _, adv, reward_stats = self._rewards_jit(
+                    traj.x0, cond_meta, self._take_reward_params())
+            else:
+                _, adv, reward_stats = self._rewards_jit(traj.x0, cond_meta)
         extras = self.update_extras()
-        self.state, metrics = self._update_jit(self.state, traj, adv, k_u,
-                                               extras)
+        with jax.profiler.TraceAnnotation("repro.trainer.update"):
+            self.state, metrics = self._update_jit(self.state, traj, adv,
+                                                   k_u, extras)
         metrics.update(reward_stats)
         return metrics
 

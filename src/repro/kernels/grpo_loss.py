@@ -64,6 +64,7 @@ def grpo_loss(logp_new: jax.Array, logp_old: jax.Array, adv: jax.Array,
             jax.ShapeDtypeStruct((B + pad,), F32),
         ],
         interpret=interpret,
+        name="grpo_loss",
     )(p(logp_new), p(logp_old), p(adv), mean)
     return loss[:B], frac[:B]
 

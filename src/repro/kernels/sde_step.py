@@ -97,6 +97,7 @@ def sde_step(v: jax.Array, x: jax.Array, eps: jax.Array, t: jax.Array,
             jax.ShapeDtypeStruct((B, 1, LANES), F32),
         ],
         interpret=interpret,
+        name="sde_step",
     )(tile(v), tile(x), tile(eps), scalars)
     x_next = x_next.reshape(B, rows * LANES)[:, :feat]
     return x_next.reshape(x.shape), logp[:, 0, 0]

@@ -118,19 +118,28 @@ class Backbone:
         p = self._gather(p)
         aux: Dict[str, jax.Array] = {}
         if cfg.family == "dit" and cond is not None:
-            mod = jnp.einsum("bd,de->be", cond, p["ada"],
-                             preferred_element_type=F32).astype(x.dtype)
-            (sh_a, sc_a, g_a, sh_m, sc_m, g_m) = jnp.split(mod, 6, axis=-1)
-            h = layers.rmsnorm(p["ln1"], x, cfg.norm_eps)
-            h = h * (1 + sc_a[:, None]) + sh_a[:, None]
+            # named scopes (HLO op_name metadata only) let a profile split
+            # the block into its modulation, attention and MLP
+            with jax.named_scope("dit_adaln"):
+                mod = jnp.einsum("bd,de->be", cond, p["ada"],
+                                 preferred_element_type=F32).astype(x.dtype)
+                (sh_a, sc_a, g_a, sh_m, sc_m, g_m) = jnp.split(mod, 6,
+                                                               axis=-1)
+                h = layers.rmsnorm(p["ln1"], x, cfg.norm_eps)
+                h = h * (1 + sc_a[:, None]) + sh_a[:, None]
             attn_fn = mla.apply_full if cfg.mla else attention.apply_full
-            a_out, cache = attn_fn(p["attn"], cfg, h, causal=causal,
-                                   window=window, positions=positions,
-                                   return_cache=return_cache)
-            x = x + g_a[:, None] * a_out
-            h = layers.rmsnorm(p["ln2"], x, cfg.norm_eps)
-            h = h * (1 + sc_m[:, None]) + sh_m[:, None]
-            x = x + g_m[:, None] * layers.mlp(p["ffn"], h)
+            with jax.named_scope("dit_attention"):
+                a_out, cache = attn_fn(p["attn"], cfg, h, causal=causal,
+                                       window=window, positions=positions,
+                                       return_cache=return_cache)
+            with jax.named_scope("dit_adaln"):
+                x = x + g_a[:, None] * a_out
+                h = layers.rmsnorm(p["ln2"], x, cfg.norm_eps)
+                h = h * (1 + sc_m[:, None]) + sh_m[:, None]
+            with jax.named_scope("dit_mlp"):
+                f_out = layers.mlp(p["ffn"], h)
+            with jax.named_scope("dit_adaln"):
+                x = x + g_m[:, None] * f_out
             return x, cache, aux
         h = layers.rmsnorm(p["ln1"], x, cfg.norm_eps)
         attn_fn = mla.apply_full if cfg.mla else attention.apply_full

@@ -6,9 +6,9 @@ the trade observable without running anything — the update is AOT-lowered
 on ``ShapeDtypeStruct``s and compiled, and the analysis byte counts are
 returned (``temp`` is the interesting one: scratch + activation buffers,
 where the loss backward's per-step residuals live).  Used by
-``BaseTrainer.memory_stats``, the ``perf.log_memory`` launcher line, the
-``benchmarks/train_step.py`` trajectory, and the tests/test_perf.py
-regression that peak temp bytes strictly drop under ``remat="scan"``.
+``BaseTrainer.memory_stats``, the ``perf.log_memory`` launcher line and
+the tests/test_perf.py regression that peak temp bytes strictly drop
+under ``remat="scan"``.
 """
 from __future__ import annotations
 

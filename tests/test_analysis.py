@@ -305,6 +305,31 @@ def train(step_fn, state, batch):
     assert findings == []
 
 
+def test_r005_with_body_is_walked_in_line(tmp_path):
+    # a donating call inside a with-block: reassigning the result there is
+    # clean, reading the donated buffer after the block is flagged
+    clean = lint(tmp_path, """\
+import jax
+
+def train(step_fn, state, batch, span):
+    step = jax.jit(step_fn, donate_argnums=0)
+    with span("update"):
+        state = step(state, batch)
+    return state["metrics"]
+""")
+    assert clean == []
+    flagged = lint(tmp_path, """\
+import jax
+
+def train(step_fn, state, batch, span):
+    step = jax.jit(step_fn, donate_argnums=0)
+    with span("update"):
+        new_state = step(state, batch)
+    return new_state, state["metrics"]
+""")
+    assert rules_of(flagged) == ["R005"]
+
+
 # ------------------------------------------------------------------- R006
 
 def test_r006_unhashable_static_arg_flagged(tmp_path):

@@ -10,6 +10,7 @@ file.  The persistent compilation cache is off around the compiles (a
 cached entry for a described device cannot be read back without a chip).
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,7 +61,10 @@ def test_sde_step_compiles(one_chip, shape):
     compiled = jax.jit(
         lambda v, x, e, t, tn: sde_step(v, x, e, t, tn, eta=0.7)
     ).lower(x, x, x, t, t).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's name, which a profile's operation events carry
+    assert re.search(r"%sde_step(\.\d+)? = ", text)
 
 
 @pytest.mark.parametrize("B", [16, 1024])
@@ -71,7 +75,9 @@ def test_grpo_loss_compiles(one_chip, kernel, B):
                                                            False)}[kernel]
     x = _struct((B,), F32, one_chip)
     compiled = jax.jit(fn).lower(x, x, x).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%grpo_loss(\.\d+)? = ", text)
 
 
 def test_velocity_full_width_fits(one_chip):
