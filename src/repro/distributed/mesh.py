@@ -25,9 +25,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.config import DistConfig
-
-DATA_AXIS = "data"
-MODEL_AXIS = "model"
+from repro.sharding import DATA_AXIS, MODEL_AXIS
 
 
 def _resolve_axis(name: str, requested: int, available: int,

@@ -14,18 +14,18 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.mesh import DATA_AXIS
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.grpo_loss import grpo_loss as _grpo
 from repro.kernels.sde_step import sde_step as _sde
 from repro.kernels.ssd_scan import ssd_scan as _ssd
+from repro.sharding import DATA_AXIS, split_axis
 
 
 def _mode() -> str:
@@ -56,9 +56,7 @@ def _batch_parallel(kernel, n_batched, *args):
     partition a Mosaic kernel by itself.  Without such a mesh, or inside a
     ``shard_map`` that already owns the data axis, the call is direct."""
     mesh = jax.sharding.get_abstract_mesh()
-    if (mesh.empty or DATA_AXIS not in mesh.axis_names
-            or DATA_AXIS in mesh.manual_axes
-            or mesh.shape[DATA_AXIS] == 1):
+    if split_axis(mesh, DATA_AXIS) is None:
         return kernel(*args)
     specs = tuple(P(DATA_AXIS) if i < n_batched else P()
                   for i in range(len(args)))
@@ -67,11 +65,24 @@ def _batch_parallel(kernel, n_batched, *args):
         *(jnp.asarray(a) for a in args))
 
 
-def flash_attention(q, k, v, *, causal=True, window=0):
-    if pallas_enabled():
-        return _flash(q, k, v, causal=causal, window=window,
-                      interpret=_interpret())
-    return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
+def flash_attention(q, k, v, *, causal=True, window=0,
+                    data_axis: Optional[str] = None,
+                    model_axis: Optional[str] = None):
+    """q: (B, Sq, H, D); k, v: (B, Sk, K, D).  Given ``data_axis`` or
+    ``model_axis``, the kernel runs through ``shard_map`` over the mesh
+    being traced under, the batch split on the one and the heads on the
+    other; the caller says where that holds
+    (``models.attention.flash_kernel_axes``)."""
+    if not pallas_enabled():
+        return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
+    kernel = functools.partial(_flash, causal=causal, window=window,
+                               interpret=_interpret())
+    if data_axis is None and model_axis is None:
+        return kernel(q, k, v)
+    spec = P(data_axis, None, model_axis, None)
+    return jax.shard_map(kernel, mesh=jax.sharding.get_abstract_mesh(),
+                         in_specs=(spec,) * 3, out_specs=spec,
+                         check_vma=False)(q, k, v)
 
 
 def ssd_scan(x, dt, a, bm, cm, *, chunk=128):

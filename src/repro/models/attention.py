@@ -17,9 +17,11 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import sharding as shlib
 from repro.config import ArchConfig
+from repro.kernels import ops
 from repro.models import layers
-from repro.models.params import P
+from repro.models.params import P, model_shard_dim
 
 
 class KVCache(NamedTuple):
@@ -58,6 +60,37 @@ def _qkv(p: Dict, cfg: ArchConfig, x: jax.Array,
     return q, k, v
 
 
+def flash_kernel_axes(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                      causal: bool, window: int
+                      ) -> Optional[Tuple[Optional[str], Optional[str]]]:
+    """The (data, model) mesh axes over which ``apply_full`` runs the core
+    of attention (scores, softmax, PV) in the Pallas flash kernel, each None
+    where it is not split; None where ``layers.attention_chunked`` runs
+    instead.  The kernel takes bidirectional attention without a window,
+    with equal q and v head sizes that are whole lane tiles (128), with
+    Pallas on.  Under a mesh it runs per shard: the batch on the data axis,
+    and the heads and kv heads on the model axis where the PartitionPlan
+    splits the projections (``params.model_shard_dim``).  A mesh it cannot
+    split over so, or a ``shard_map`` that owns some axes while others are
+    left to split, keeps the chunked path."""
+    D = q.shape[-1]
+    if not (ops.pallas_enabled() and not causal and window == 0
+            and D == v.shape[-1] and D % 128 == 0):
+        return None
+    mesh = jax.sharding.get_abstract_mesh()
+    data = shlib.split_axis(mesh, shlib.DATA_AXIS)
+    model = shlib.split_axis(mesh, shlib.MODEL_AXIS)
+    if (data or model) and mesh.manual_axes:
+        return None
+    if data and q.shape[0] % mesh.shape[data]:
+        return None
+    if model and any(model_shard_dim((n,), (name,), mesh.shape[model]) != 0
+                     for n, name in ((q.shape[2], "heads"),
+                                     (k.shape[2], "kv_heads"))):
+        return None
+    return data, model
+
+
 def apply_full(p: Dict, cfg: ArchConfig, x: jax.Array, *,
                causal: bool = True, window: int = 0,
                positions: Optional[jax.Array] = None,
@@ -68,8 +101,14 @@ def apply_full(p: Dict, cfg: ArchConfig, x: jax.Array, *,
     if positions is None:
         positions = jnp.arange(S, dtype=jnp.int32)
     q, k, v = _qkv(p, cfg, x, positions)
-    o = layers.attention_chunked(q, k, v, causal=causal, window=window,
-                                 q_positions=positions, k_positions=positions)
+    axes = flash_kernel_axes(q, k, v, causal=causal, window=window)
+    if axes is None:
+        o = layers.attention_chunked(q, k, v, causal=causal, window=window,
+                                     q_positions=positions,
+                                     k_positions=positions)
+    else:
+        o = ops.flash_attention(q, k, v, causal=False, data_axis=axes[0],
+                                model_axis=axes[1])
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"],
                      preferred_element_type=layers.reduce_dtype()
                      ).astype(x.dtype)

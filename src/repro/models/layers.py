@@ -87,8 +87,9 @@ def mlp(p: dict, x: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Attention math (reference/jnp path; Pallas kernel path lives in
-# repro.kernels and is dispatched by repro.kernels.ops on TPU)
+# Attention math (jnp path).  ``attention.apply_full`` runs bidirectional
+# attention at head sizes of 128 in the Pallas flash kernel instead where
+# Pallas is on (``attention.flash_kernel_axes``); everything else runs here
 # ---------------------------------------------------------------------------
 
 NEG_INF = -0.7 * jnp.finfo(jnp.float32).max

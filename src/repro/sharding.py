@@ -25,6 +25,20 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 Axes = Tuple[Optional[str], ...]
 RuleTable = Dict[str, Union[str, Tuple[str, ...], None]]
 
+DATA_AXIS = "data"
+MODEL_AXIS = "model"
+
+
+def split_axis(mesh, name: str) -> Optional[str]:
+    """``name`` where the abstract ``mesh`` (``jax.sharding.
+    get_abstract_mesh()``) has that axis over more than one device and a
+    ``shard_map`` may still split over it (it is not already manual);
+    otherwise None."""
+    if (mesh.empty or name not in mesh.axis_names
+            or name in mesh.manual_axes or mesh.shape[name] == 1):
+        return None
+    return name
+
 
 def _moe_mode() -> str:
     import os

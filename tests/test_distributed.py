@@ -512,3 +512,59 @@ def test_pallas_kernels_split_over_the_data_axis_under_a_mesh():
     shard_map, with the same results as the reference."""
     out = _run_with_host_devices(_MESH_KERNEL_SCRIPT)
     assert "MESH-KERNEL-OK" in out
+
+
+_MESH_ATTENTION_SCRIPT = r"""
+import os
+os.environ["REPRO_PALLAS"] = "interpret"
+import jax, jax.numpy as jnp
+import numpy as np
+from repro import distributed
+from repro.config import ArchConfig, DistConfig
+from repro.distributed.sharding import _on_mesh, batch_sharding
+from repro.models import attention
+from repro.models import params as params_lib
+
+mesh = distributed.train_mesh(DistConfig(data_parallel=2, model_parallel=2))
+key = jax.random.PRNGKey(0)
+x = jax.random.normal(key, (4, 24, 64))
+
+for heads, kernel in ((2, True), (3, False)):
+    cfg = ArchConfig(name="tiny-dit", family="dit", n_layers=1, d_model=64,
+                     n_heads=heads, n_kv_heads=heads, d_ff=128,
+                     vocab_size=16, head_dim=128, qk_norm=True)
+    p = params_lib.init(attention.spec(cfg), key, jnp.float32)
+
+    def loss(p, x):
+        out = attention.apply_full(p, cfg, x, causal=False)[0]
+        return (out ** 2).sum(), out
+
+    step = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+    fn = jax.jit(_on_mesh(step, mesh),
+                 in_shardings=(None, batch_sharding(mesh, 0)))
+    xs = jax.device_put(x, batch_sharding(mesh, 0))
+    text = str(fn.trace(p, xs).jaxpr)
+    # heads split on the model axis: the kernel per (data, model) shard;
+    # 3 heads do not split over 2: the chunked path, decided from the mesh
+    assert ("pallas_call" in text) == kernel, heads
+    assert ("shard_map" in text) == kernel, heads
+    (_, out), grads = fn(p, xs)
+    os.environ["REPRO_PALLAS"] = "off"
+    (_, want), want_grads = jax.jit(step)(p, x)
+    os.environ["REPRO_PALLAS"] = "interpret"
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    # f32, summed in another order across the shards
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * float(np.abs(w).max()))
+print("MESH-ATTENTION-OK")
+"""
+
+
+def test_dit_attention_kernel_runs_per_shard_under_a_mesh():
+    """Under the dp=2 x mp=2 mesh the dit attention runs the flash kernel
+    per shard (batch on the data axis, heads on the model axis) where the
+    heads split, and stays on the chunked path where they do not; the
+    values and gradients are the single-device reference's."""
+    out = _run_with_host_devices(_MESH_ATTENTION_SCRIPT)
+    assert "MESH-ATTENTION-OK" in out

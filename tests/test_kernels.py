@@ -100,6 +100,88 @@ def test_kernel_matches_model_attention_path():
     np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-4)
 
 
+def _qkv(B, S, H, K, D, dtype):
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32).astype(dtype)
+    k = jax.random.normal(ks[1], (B, S, K, D), jnp.float32).astype(dtype)
+    v = jax.random.normal(ks[2], (B, S, K, D), jnp.float32).astype(dtype)
+    do = jax.random.normal(ks[3], (B, S, H, D), jnp.float32).astype(dtype)
+    return q, k, v, do
+
+
+# the velocity's shape, cut: D = 128, a length that is no multiple of 128;
+# the default blocks take the whole key length as one block, the small ones
+# the online softmax over a padded, masked key tail
+BIDIRECTIONAL = pytest.mark.parametrize("B,S,H,K", [(2, 272, 4, 2),
+                                                   (2, 272, 4, 4)])
+BLOCKS = pytest.mark.parametrize("blocks", [{}, {"block_q": 64,
+                                                 "block_k": 128}],
+                                 ids=["whole_kv", "kv_blocks"])
+DTYPES = pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+
+
+def _tol(dtype):
+    return 1e-5 if dtype == jnp.float32 else 2e-2
+
+
+@BIDIRECTIONAL
+@BLOCKS
+@DTYPES
+def test_bidirectional_flash_matches_model_path(B, S, H, K, blocks, dtype):
+    from repro.models.layers import attention_chunked
+    q, k, v, _ = _qkv(B, S, H, K, 128, dtype)
+    out = flash_attention(q, k, v, causal=False, interpret=True, **blocks)
+    want = attention_chunked(q, k, v, causal=False)
+    assert out.dtype == want.dtype
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               want.astype(jnp.float32),
+                               atol=_tol(dtype), rtol=_tol(dtype))
+
+
+@BIDIRECTIONAL
+@BLOCKS
+@DTYPES
+def test_flash_vjp_matches_autodiff_of_model_path(B, S, H, K, blocks, dtype):
+    """The custom VJP's dq, dk, dv against jax's autodiff of the chunked
+    attention the kernel replaces."""
+    from repro.models.layers import attention_chunked
+    q, k, v, do = _qkv(B, S, H, K, 128, dtype)
+    _, kern_vjp = jax.vjp(lambda *a: flash_attention(
+        *a, causal=False, interpret=True, **blocks), q, k, v)
+    _, ref_vjp = jax.vjp(lambda *a: attention_chunked(*a, causal=False),
+                         q, k, v)
+    for name, got, want in zip("qkv", kern_vjp(do), ref_vjp(do)):
+        assert got.dtype == want.dtype, name
+        np.testing.assert_allclose(got.astype(jnp.float32),
+                                   want.astype(jnp.float32),
+                                   atol=_tol(dtype), rtol=_tol(dtype),
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 64)])
+def test_flash_vjp_masked(causal, window):
+    """The backward applies the forward's masks (GQA, D = 64, f32)."""
+    from repro.models.layers import attention_chunked
+    q, k, v, do = _qkv(1, 200, 4, 2, 64, jnp.float32)
+    kw = {"causal": causal, "window": window}
+    _, kern_vjp = jax.vjp(lambda *a: flash_attention(
+        *a, block_q=64, block_k=64, interpret=True, **kw), q, k, v)
+    _, ref_vjp = jax.vjp(lambda *a: attention_chunked(*a, **kw), q, k, v)
+    for got, want in zip(kern_vjp(do), ref_vjp(do)):
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("sq,sk,want", [
+    (1040, 1040, (520, 1040)),      # FLUX 512px: 16 + 1024 tokens
+    (272, 272, (272, 272)),
+    (4112, 4112, (1376, 512)),      # FLUX 1024px: kv blocks, padded q
+    (20, 20, (24, 20)),
+])
+def test_blocks_follow_the_shape(sq, sk, want):
+    from repro.kernels.flash_attention import choose_blocks
+    assert tuple(choose_blocks(sq, sk)) == want
+
+
 def test_trainer_kernel_path_equivalence(monkeypatch):
     """The GRPO trainer produces identical losses/gradients whether the SDE
     step + GRPO loss run through the Pallas kernels (interpret mode) or the
@@ -125,6 +207,22 @@ def test_trainer_kernel_path_equivalence(monkeypatch):
                          float(m["grad_norm"]))
     np.testing.assert_allclose(results["off"], results["interpret"],
                                atol=2e-3)
+
+
+def test_split_axis_follows_the_mesh():
+    """The kernels split over a mesh axis only where it has more than one
+    device and is not already manual; without a mesh, over none."""
+    from jax.sharding import AbstractMesh, AxisType
+    from repro.sharding import split_axis
+    mesh = AbstractMesh((2, 1), ("data", "model"))
+    assert split_axis(mesh, "data") == "data"
+    assert split_axis(mesh, "model") is None
+    assert split_axis(mesh, "pod") is None
+    assert split_axis(jax.sharding.get_abstract_mesh(), "data") is None
+    manual = AbstractMesh((2, 2), ("data", "model"),
+                          axis_types=(AxisType.Manual, AxisType.Auto))
+    assert split_axis(manual, "data") is None
+    assert split_axis(manual, "model") == "model"
 
 
 class TestOpsDispatchEquivalence:
@@ -260,6 +358,86 @@ class TestOpsDispatchEquivalence:
         assert wrappers <= covered, \
             f"ops wrappers without dispatch-equivalence tests: " \
             f"{sorted(wrappers - covered)}"
+
+
+def _attention_case(head_dim):
+    from repro.config import ArchConfig
+    from repro.models import attention
+    from repro.models import params as params_lib
+    cfg = ArchConfig(name="tiny-dit", family="dit", n_layers=1, d_model=64,
+                     n_heads=2, n_kv_heads=2, d_ff=128, vocab_size=16,
+                     head_dim=head_dim, qk_norm=True)
+    p = params_lib.init(attention.spec(cfg), KEY, jnp.float32)
+    x = jax.random.normal(KEY, (2, 40, 64))
+    return cfg, p, x
+
+
+class TestAttentionDispatch:
+    """``attention.apply_full`` runs bidirectional attention at head sizes
+    of 128 in the flash kernel where Pallas is on, and everything else in
+    ``layers.attention_chunked``, with the same results."""
+
+    @pytest.mark.parametrize("causal,window,head_dim,kernel", [
+        (False, 0, 128, True),
+        (True, 0, 128, False),
+        (False, 16, 128, False),
+        (False, 0, 64, False),
+    ], ids=["bidirectional", "causal", "windowed", "d64"])
+    def test_apply_full_takes_the_kernel(self, monkeypatch, causal, window,
+                                         head_dim, kernel):
+        from repro.models import attention
+        cfg, p, x = _attention_case(head_dim)
+
+        def run(mode):
+            monkeypatch.setenv("REPRO_PALLAS", mode)
+            fn = lambda p, x: attention.apply_full(p, cfg, x, causal=causal,
+                                                   window=window)[0]
+            return str(jax.make_jaxpr(fn)(p, x)), fn(p, x)
+
+        text, got = run("interpret")
+        assert ("pallas_call" in text) == kernel
+        _, want = run("off")
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+    def test_velocity_unchanged_with_pallas_off(self, monkeypatch):
+        """With Pallas off, the dit velocity at head size 128 (which the
+        kernel would take) is bit-identical to one whose every block calls
+        ``attention_chunked`` directly, as the velocity did before the
+        kernel was wired in."""
+        import dataclasses
+        from repro import configs
+        from repro.config import FlowRLConfig
+        from repro.models import attention, layers
+        from repro.models import params as params_lib
+        from repro.models.flow import FlowAdapter
+        arch = dataclasses.replace(configs.get_reduced("flux_dit"),
+                                   d_model=128, n_heads=2, n_kv_heads=2,
+                                   head_dim=128, d_ff=256)
+        adapter = FlowAdapter(arch, FlowRLConfig(latent_tokens=24,
+                                                 latent_dim=8), cond_dim=32)
+        params = params_lib.init(adapter.spec(), KEY, jnp.bfloat16)
+        ks = jax.random.split(KEY, 3)
+        x = jax.random.normal(ks[0], (2, 24, 8))
+        t = jax.random.uniform(ks[1], (2,))
+        cond = jax.random.normal(ks[2], (2, 4, 32))
+        monkeypatch.setenv("REPRO_PALLAS", "off")
+        got = jax.jit(adapter.velocity)(params, x, t, cond)
+
+        def chunked_apply_full(p, cfg, x, *, causal=True, window=0,
+                               positions=None, return_cache=False):
+            positions = jnp.arange(x.shape[1], dtype=jnp.int32)
+            q, k, v = attention._qkv(p, cfg, x, positions)
+            o = layers.attention_chunked(q, k, v, causal=causal,
+                                         window=window,
+                                         q_positions=positions,
+                                         k_positions=positions)
+            return jnp.einsum("bshk,hkd->bsd", o, p["wo"],
+                              preferred_element_type=layers.reduce_dtype()
+                              ).astype(x.dtype), None
+
+        monkeypatch.setattr(attention, "apply_full", chunked_apply_full)
+        want = jax.jit(adapter.velocity)(params, x, t, cond)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_grpo_loss_diff_gradient():

@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs
 from repro.config import FlowRLConfig
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.grpo_loss import grpo_loss, grpo_loss_diff
 from repro.kernels.sde_step import sde_step
 from repro.models import params as params_lib
@@ -80,6 +81,38 @@ def test_grpo_loss_compiles(one_chip, kernel, B):
     assert re.search(r"%grpo_loss(\.\d+)? = ", text)
 
 
+# FLUX.1 at 512px: 16 trajectories of 16 condition + 1024 latent tokens,
+# 24 heads of 128
+FLUX_ATTENTION = (16, 1040, 24, 128)
+
+
+@pytest.mark.parametrize("seq", [1040, 4112], ids=["flux_512px",
+                                                    "flux_1024px"])
+def test_flash_attention_compiles(one_chip, seq):
+    """At 512px the whole key length is one kv block; at 1024px (4096
+    latent tokens) the kv-blocked path with its masked key tail."""
+    x = _struct((16, seq) + FLUX_ATTENTION[2:], jnp.bfloat16, one_chip)
+    compiled = jax.jit(
+        lambda q, k, v: flash_attention(q, k, v, causal=False)
+    ).lower(x, x, x).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%flash_attention(\.\d+)? = ", text)
+
+
+def test_flash_attention_gradient_compiles(one_chip):
+    """Forward and custom VJP at the update's batch of one."""
+    x = _struct((1,) + FLUX_ATTENTION[1:], jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=False)
+        return (o.astype(F32) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    assert re.search(r"%flash_attention(\.\d+)? = ", compiled.as_text())
+
+
 def test_velocity_full_width_fits(one_chip):
     """flux_dit's velocity forward at its published widths (one layer) at
     the smoke run's batch: 16 trajectories of FLUX's 1024x64 packed
@@ -99,3 +132,79 @@ def test_velocity_full_width_fits(one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert arch.d_model == 3072 and arch.d_ff == 12288
     assert 0 < total < V5E_HBM_BYTES, total
+
+
+def _instructions(text):
+    """{name: (opcode, operand names)} of a compiled HLO module's text."""
+    out = {}
+    for m in re.finditer(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*)$", text, re.M):
+        op = re.search(r"[}\])] ([\w\-]+)\(([^)]*)\)", m.group(2))
+        if op:
+            out[m.group(1)] = (op.group(1),
+                               re.findall(r"%([\w.\-]+)", op.group(2)))
+    return out
+
+
+def _kernel_neighbours(text, kernel):
+    """The instructions that make the operands of ``kernel`` and that
+    read its result, looking through bitcasts: (opcode, name) pairs."""
+    ins = _instructions(text)
+
+    def producer(name):
+        while ins[name][0] == "bitcast":
+            name = ins[name][1][0]
+        return ins[name][0], name
+
+    def consumers(name):
+        for user, (op, args) in ins.items():
+            if name in args:
+                yield from (consumers(user) if op == "bitcast"
+                            else [(op, user)])
+
+    return ([producer(a) for a in ins[kernel][1]]
+            + list(consumers(kernel)))
+
+
+def _is_relayout(opcode, name):
+    return (opcode in ("copy", "transpose")
+            or re.match(r"(copy|transpose)", name) is not None)
+
+
+def test_relayout_is_seen_around_a_kernel(one_chip):
+    """The layout check below sees a relayout: a kernel whose operands
+    arrive as (B, S, H, D) in the default layout gets transposes."""
+    x = _struct(FLUX_ATTENTION, jnp.bfloat16, one_chip)
+    text = jax.jit(
+        lambda q, k, v: flash_attention(q, k, v, causal=False)
+    ).lower(x, x, x).compile().as_text()
+    call = re.search(r"%(flash_attention(?:\.\d+)?) = ", text).group(1)
+    assert any(_is_relayout(*n) for n in _kernel_neighbours(text, call))
+
+
+def test_velocity_runs_the_kernel_in_its_scope(one_chip, monkeypatch):
+    """With the kernels on, as on the chip, the velocity's bidirectional
+    attention compiles to one flash kernel call for its one block, inside
+    the ``dit_attention`` scope that ``attention_ms`` reads.  The kernel's
+    (B*H, S, D) operands and result are bitcasts of what the projections
+    make and the output projection reads: no copy or transpose feeds it or
+    reads it, at FLUX widths."""
+    monkeypatch.setenv("REPRO_PALLAS", "on")
+    arch = dataclasses.replace(configs.get("flux_dit"), n_layers=1)
+    adapter = FlowAdapter(arch, FlowRLConfig(latent_tokens=1024,
+                                             latent_dim=64), cond_dim=512)
+    params = jax.tree.map(lambda s: _struct(s.shape, s.dtype, one_chip),
+                          params_lib.shape_tree(adapter.spec(),
+                                                jnp.bfloat16))
+    x = _struct((16, 1024, 64), F32, one_chip)
+    t = _struct((16,), F32, one_chip)
+    cond = _struct((16, 16, 512), F32, one_chip)
+    text = jax.jit(adapter.velocity).lower(params, x, t, cond).compile(
+        ).as_text()
+    calls = re.findall(r"%flash_attention(?:\.\d+)? = .*", text)
+    assert len(calls) == 1
+    op_name = re.search(r'op_name="([^"]*)"', calls[0]).group(1)
+    assert "dit_attention" in op_name.split("/"), op_name
+    call = re.match(r"%([\w.]+)", calls[0]).group(1)
+    neighbours = _kernel_neighbours(text, call)
+    assert len(neighbours) >= 4, neighbours
+    assert not [n for n in neighbours if _is_relayout(*n)], neighbours
